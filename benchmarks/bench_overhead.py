@@ -805,7 +805,7 @@ def mesh_sweep(ndevs=(1, 2, 4), depth: int = 96):
                      "--xla_force_host_platform_device_count")]
         flags.append(f"--xla_force_host_platform_device_count={ndev}")
         env["XLA_FLAGS"] = " ".join(flags)
-        env["JAX_PLATFORM_NAME"] = "cpu"
+        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = os.pathsep.join(
             p for p in (os.path.join(root, "src"), root,
                         env.get("PYTHONPATH")) if p)

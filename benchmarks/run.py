@@ -15,10 +15,9 @@ admission-contract audit) under ``"serve"``.
 ``--only`` takes comma-separated substrings (``--only fig5,serve``).
 
 Sections are imported lazily, one at a time: a module that fails to import
-is reported as SKIPPED with its traceback instead of aborting the whole
-harness (or worse, vanishing silently), and the run exits nonzero when
-*every* selected section was skipped — a harness that ran nothing must not
-look green.
+is reported with its traceback and counts as a failed section (nonzero
+exit) — the remaining sections still run, but a section that could not
+even load never looks green.
 """
 import argparse
 import importlib
@@ -61,21 +60,18 @@ def run(only=None, smoke=False, out_path=OVERHEAD_JSON, sections=None):
     jax.config.update("jax_cpu_enable_async_dispatch", False)
 
     failures = []
-    skipped = []
     payloads = {}
-    selected = 0
     patterns = [p for p in (only or "").split(",") if p]
     for name, module_path in (ALL if sections is None else sections):
         if patterns and not any(p in name for p in patterns):
             continue
-        selected += 1
         print(f"\n== {name} ==")
         try:
             fn = importlib.import_module(module_path).main
-        except Exception as e:  # broken module: loud skip, keep going
+        except Exception as e:  # broken module: a failure; keep going
             traceback.print_exc()
-            print(f"-- SKIPPED {name}: cannot import {module_path}: {e!r}")
-            skipped.append((name, repr(e)))
+            print(f"-- FAILED {name}: cannot import {module_path}: {e!r}")
+            failures.append((name, repr(e)))
             continue
         kwargs = {}
         if smoke and "smoke" in inspect.signature(fn).parameters:
@@ -101,17 +97,10 @@ def run(only=None, smoke=False, out_path=OVERHEAD_JSON, sections=None):
         with open(out_path, "w") as f:
             json.dump(doc, f, indent=2, sort_keys=True)
         print(f"\nwrote {out_path}")
-    if skipped:
-        print("\nBENCH SKIPPED (import failures):", skipped)
     if failures:
         print("\nBENCH FAILURES:", failures)
         return 1
-    if selected and len(skipped) == selected:
-        print("\nevery selected benchmark section was skipped — "
-              "treating an all-skip run as failure")
-        return 1
-    print("\nall benchmarks passed"
-          + (f" ({len(skipped)} section(s) skipped)" if skipped else ""))
+    print("\nall benchmarks passed")
     return 0
 
 

@@ -1,7 +1,8 @@
 """Repo-wide pytest configuration for deterministic CI runs.
 
-* Forces ``jax_platform_name=cpu`` (set before jax initialises) so the suite
-  behaves identically on dev boxes, CI runners and TPU hosts.
+* Restricts JAX to the CPU backend (``JAX_PLATFORMS=cpu``, set before jax
+  initialises) so the suite behaves identically on dev boxes, CI runners
+  and TPU hosts, and a test process never takes a host's TPU.
 * Seeds every stdlib/numpy RNG and pins a session PRNG key fixture, so runs
   are reproducible bit-for-bit.
 * Prepends ``src/`` to ``sys.path`` so ``pytest`` works from a clean checkout
@@ -11,7 +12,7 @@ import os
 import random
 import sys
 
-os.environ.setdefault("JAX_PLATFORM_NAME", "cpu")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=1")
 
 _SRC = os.path.join(os.path.dirname(__file__), "src")

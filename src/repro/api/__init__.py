@@ -14,7 +14,8 @@ store/prefetch by the executor — O(n/I) host dispatches per pass.  Pass
 ``engine="interpreted"`` for the step-granular interpreter, or
 ``engine="scan"`` for the trace-native path (one XLA call, composes with
 ``jax.jit`` / ``jax.vmap`` / mesh sharding) — all engines execute the
-same ``SegmentPlan`` (``api.last_plan()``).
+same ``SegmentPlan`` (``api.last_plan()``).  On a TPU only the scan engine
+runs, and it is the default there (``api.default_engine()``).
 
 See ``repro.api.frontend`` for the transform, ``repro.api.chain`` for the
 chain decomposition it differentiates, and ``repro.api.autotune`` for the
@@ -25,7 +26,7 @@ from repro.api.autotune import AutoTuner, GLOBAL_TUNER, TuneResult
 from repro.api.chain import ChainSpec, chain_length
 from repro.api.frontend import (ENGINES, STORAGE_KINDS, STRATEGIES,
                                 OffloadConfig, checkpointed_bptt,
-                                last_plan, last_stats, last_tune,
+                                default_engine, last_plan, last_stats, last_tune,
                                 offloaded_loss, resume_offloaded,
                                 value_and_grad_offloaded)
 from repro.core.faults import StorageFault  # typed Level-2 failure root
@@ -37,7 +38,8 @@ __all__ = [
     "ChainSpec", "chain_length",
     "ENGINES", "STORAGE_KINDS", "STRATEGIES",
     "InnerPlan", "Plan2D", "choose_2d_plan",
-    "OffloadConfig", "StorageFault", "checkpointed_bptt", "last_plan",
+    "OffloadConfig", "StorageFault", "checkpointed_bptt", "default_engine",
+    "last_plan",
     "last_stats", "last_tune",
     "offloaded_loss", "resume_offloaded", "value_and_grad_offloaded",
 ]

@@ -38,14 +38,15 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import SingleDeviceSharding
 
 from repro.core import offload as ofl
-from repro.core.perfmodel import (KNL, TPU_V5E, HardwareSpec, StepTimes,
+from repro.core.perfmodel import (HardwareSpec, StepTimes,
                                   choose_interval_with_params,
                                   choose_sharded_interval,
                                   choose_tiered_interval,
-                                  effective_transfer_time, optimal_interval,
-                                  times_from_roofline)
+                                  effective_transfer_time, hardware_for,
+                                  optimal_interval, times_from_roofline)
 from repro.core.storage import TieredStorage, tree_bytes
 
 
@@ -405,18 +406,14 @@ class AutoTuner:
         """
         state_bytes = _aval_bytes(carry0)
         offloads = ofl.host_offload_supported()
-        hw = TPU_V5E if jax.default_backend() == "tpu" else KNL
-        level2 = "xla_host" if offloads else f"roofline-{hw.name}"
+        device = jax.devices()[0]
+        level2 = "xla_host" if offloads else \
+            f"roofline-{hardware_for(device).name}"
         cached = self.lookup(name, n, state_bytes, level2)
         if cached is not None:
             return cached
 
         segment_len = max(1, min(segment_len, n))
-        zp, zc, zb = _zeros_of(params), _zeros_of(carry0), _zeros_of(batch)
-        zxs = jax.tree_util.tree_map(
-            lambda leaf: jnp.zeros(
-                (segment_len,) + tuple(np.shape(leaf)[1:]), _aval_dtype(leaf)),
-            xs)
 
         @jax.jit
         def probe(p, c, xs_, b):
@@ -426,20 +423,30 @@ class AutoTuner:
             c, _ = lax.scan(step, c, xs_)
             return c
 
-        t_a = self._time(
-            lambda: jax.block_until_ready(probe(zp, zc, zxs, zb))
-        ) / segment_len
+        # The caller may be tracing (the scan engine resolves its schedule
+        # inside jit); without this the stand-ins and probes would be
+        # staged into that trace and the timings would measure tracing.
+        with jax.ensure_compile_time_eval():
+            zp, zc, zb = (_zeros_of(params), _zeros_of(carry0),
+                          _zeros_of(batch))
+            zxs = jax.tree_util.tree_map(
+                lambda leaf: jnp.zeros(
+                    (segment_len,) + tuple(np.shape(leaf)[1:]),
+                    _aval_dtype(leaf)),
+                xs)
+            t_a = self._time(
+                lambda: jax.block_until_ready(probe(zp, zc, zxs, zb))
+            ) / segment_len
 
-        if offloads:
-            mem = jax.devices()[0].memory(ofl.HOST)
+            if offloads:
+                host = SingleDeviceSharding(device, memory_kind=ofl.HOST)
 
-            def one_store():
-                jax.block_until_ready(jax.tree_util.tree_map(
-                    lambda x: jax.device_put(x, mem), zc))
+                def one_store():
+                    jax.block_until_ready(jax.device_put(zc, host))
 
-            t_t = self._time(one_store)
-        else:
-            t_t = state_bytes / hw.d2h_bw
+                t_t = self._time(one_store)
+            else:
+                t_t = state_bytes / hardware_for(device).d2h_bw
 
         interval = snap_interval(n, optimal_interval(t_t, t_a))
         slots = default_slots(interval, self.l1_budget_states)

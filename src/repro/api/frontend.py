@@ -574,10 +574,11 @@ def _resolve_inner(spec: ChainSpec, cfg: OffloadConfig, params, carry0, xs,
 def _select_runner(cfg: OffloadConfig) -> str:
     """Resolve ``cfg.runner`` against the hardware actually present.
 
-    ``runner="pallas"`` needs a Pallas lowering target (TPU, or interpret
-    mode forced via ``REPRO_PALLAS_INTERPRET=1``); anywhere else it falls
-    back to the plain compiled runner with a one-line warning so CPU CI
-    and laptops keep working untouched.
+    ``runner="pallas"`` runs its kernels in interpret mode when forced via
+    ``REPRO_PALLAS_INTERPRET=1``; on a TPU it raises, because Mosaic cannot
+    lower the fused kernels (see :func:`segment_pallas.runner_supported`);
+    anywhere else it falls back to the plain compiled runner with a
+    one-line warning so CPU CI and laptops keep working untouched.
     """
     if cfg.runner != "pallas":
         return cfg.runner
@@ -586,6 +587,8 @@ def _select_runner(cfg: OffloadConfig) -> str:
     ok, reason = sp.runner_supported()
     if ok:
         return "pallas"
+    if jax.default_backend() == "tpu":
+        raise NotImplementedError(reason)
     warnings.warn(reason, stacklevel=3)  # one line: why + the fallback
     return "compiled"
 
@@ -1115,6 +1118,13 @@ def _as_chain_spec(loss_fn) -> Optional[ChainSpec]:
     return getattr(loss_fn, "chain_spec", None)
 
 
+def default_engine() -> str:
+    """The engine :func:`value_and_grad_offloaded` runs when none is given:
+    ``"scan"`` on a TPU, where the executor engines cannot run (see
+    :func:`offloaded_loss`), and ``"compiled"`` elsewhere."""
+    return "scan" if jax.default_backend() == "tpu" else "compiled"
+
+
 def offloaded_loss(spec: ChainSpec, cfg: OffloadConfig
                    ) -> Callable[[Any, Any], Any]:
     """The loss with its chain segment rerouted through the configured
@@ -1122,10 +1132,23 @@ def offloaded_loss(spec: ChainSpec, cfg: OffloadConfig
     via custom_vjp + io_callback) or the trace-native plan-driven scan
     (``engine="scan"``).  Differentiable; prelude/readout gradients flow via
     ordinary autodiff (stacked-layer cotangents scatter back into params
-    through the prelude's vjp)."""
+    through the prelude's vjp).
+
+    The executor engines raise on a TPU.  ``io_callback`` runs its host
+    callback with the CPU as the default device and the arguments placed
+    there, so their segments would compile and run on the host CPU while
+    the chip idles; dispatching the segments to the TPU from inside the
+    callback deadlocks instead."""
 
     if cfg.engine == "scan":
         return _scan_loss(spec, cfg)
+    if jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            f"engine={cfg.engine!r} cannot run on a TPU: jax.io_callback "
+            "runs the executor's segments on the host CPU, and dispatching "
+            "them to the TPU from inside the callback deadlocks; use "
+            "engine='scan', which keeps the chain in one XLA program with "
+            "segment boundaries in pinned host memory")
 
     def loss(params, batch):
         carry0, xs = spec.prelude(params, batch)
@@ -1162,7 +1185,7 @@ def value_and_grad_offloaded(
     autotune: bool = True,
     tuner: Optional[at.AutoTuner] = None,
     fallback: bool = True,
-    engine: str = "compiled",
+    engine: Optional[str] = None,
     runner: str = "compiled",
     mesh: Optional[Any] = None,
     state_spec: Optional[Any] = None,
@@ -1221,25 +1244,28 @@ def value_and_grad_offloaded(
     :class:`repro.core.faults.StorageFault` subclasses.
 
     ``engine`` selects how segments execute — all three drive the same
-    ``SegmentPlan`` IR (``api.last_plan()``): ``"compiled"`` (default) runs
-    one jitted ``lax.scan``/checkpointed-vjp call per segment — O(n/I) host
+    ``SegmentPlan`` IR (``api.last_plan()``): ``"compiled"`` runs one
+    jitted ``lax.scan``/checkpointed-vjp call per segment — O(n/I) host
     dispatches, compiled once per segment length; ``"interpreted"`` is the
     step-granular paper-faithful interpreter (O(n) dispatches, exact
     Revolve-optimal advance counts); ``"scan"`` stays entirely inside the
     XLA trace (one dispatch, boundaries offloaded to pinned host memory by
     the compiler where supported) and composes with ``jax.jit``,
-    ``jax.vmap`` and mesh sharding — use it on pods.  The scan engine
-    implements the ``multistage_async`` strategy with the XLA host backend
-    only (``storage`` must stay ``"ram"``).
+    ``jax.vmap`` and mesh sharding.  The scan engine implements the
+    ``multistage_async`` strategy with the XLA host backend only
+    (``storage`` must stay ``"ram"``).  ``None`` picks
+    :func:`default_engine`: ``"scan"`` on a TPU, where the two executor
+    engines raise (see :func:`offloaded_loss`), ``"compiled"`` elsewhere.
 
     ``runner`` (compiled engine only) selects the per-segment kernel:
     ``"compiled"`` (default) is one jitted scan per segment with the
     boundary store issued from the host; ``"pallas"`` fuses the segment
     into a Pallas kernel that double-buffers the boundary-state DMA to
     host memory while the next chunk computes, and reverses segments with
-    Echo-style in-kernel recompute.  Requires a Pallas target (TPU, or
-    ``REPRO_PALLAS_INTERPRET=1`` for interpret mode); anywhere else it
-    falls back to ``"compiled"`` with a one-line warning.  Gradients are
+    Echo-style in-kernel recompute.  It runs in interpret mode only
+    (``REPRO_PALLAS_INTERPRET=1``): on a TPU it raises, because Mosaic
+    cannot lower the kernels' chunk loops, and anywhere else it falls back
+    to ``"compiled"`` with a one-line warning.  Gradients are
     bit-identical across runners on matching chunking (fp32).
 
     ``mesh`` (executor engines only) makes the offloaded run first-class
@@ -1332,6 +1358,8 @@ def value_and_grad_offloaded(
             stacklevel=2)
         return jax.value_and_grad(loss_fn)
 
+    if engine is None:
+        engine = default_engine()
     cfg = OffloadConfig(strategy=strategy, interval=interval, slots=slots,
                         storage=storage, storage_dir=storage_dir,
                         l2_capacity_bytes=l2_capacity_bytes,

@@ -15,11 +15,11 @@ the host.  This module centralises those knobs.
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional, Sequence
+from typing import Any, Sequence
 
 import jax
 from jax.ad_checkpoint import checkpoint_name
-from jax.sharding import NamedSharding, PartitionSpec
+from jax.sharding import NamedSharding
 
 # Residual-name vocabulary (shared with models/ and core/multistage_scan).
 BOUNDARY = "ms_boundary"          # segment-boundary carry -> Level 2
@@ -128,11 +128,11 @@ def host_offload_supported() -> bool:
     """Whether this backend/jaxlib lowers offload remat policies to host
     memory-space transfers.
 
-    TPU (and recent GPU) runtimes do; CPU builds typically reject the
-    ``TransferToMemoryKind`` placement or silently keep residuals on device.
-    Callers (``repro.api`` strategy selection, platform-dependent tests) use
-    this to fall back to the thread-based executor path, which works
-    everywhere.
+    TPU runtimes do, and so does the CPU backend of current jaxlib; older
+    CPU builds reject the ``TransferToMemoryKind`` placement.  Callers
+    (the scan engine, platform-dependent tests) keep boundaries in device
+    memory where this is False.  On a TPU a failing probe is a fault, not
+    an answer, so it raises there.
     """
     import jax.numpy as jnp
 
@@ -144,45 +144,11 @@ def host_offload_supported() -> bool:
         pol = make_policy("offload_layer")
         jaxpr = str(jax.make_jaxpr(
             jax.grad(jax.checkpoint(f, policy=pol)))(jnp.ones((2, 2))))
-        return "<host>" in jaxpr
     except Exception:
+        if jax.default_backend() == "tpu":
+            raise
         return False
-
-
-# ---------------------------------------------------------------------------
-# Explicit host placement (serving path: KV-cache paging, optimizer state)
-# ---------------------------------------------------------------------------
-
-
-def host_sharding(mesh: jax.sharding.Mesh,
-                  spec: PartitionSpec) -> NamedSharding:
-    return NamedSharding(mesh, spec, memory_kind=HOST)
-
-
-def device_sharding(mesh: jax.sharding.Mesh,
-                    spec: PartitionSpec) -> NamedSharding:
-    return NamedSharding(mesh, spec, memory_kind=DEVICE)
-
-
-def to_host(x: Any, mesh: Optional[jax.sharding.Mesh] = None,
-            spec: Optional[PartitionSpec] = None) -> Any:
-    """Move a pytree to host memory (async under jit via device_put)."""
-    if mesh is not None:
-        sh = host_sharding(mesh, spec if spec is not None else PartitionSpec())
-        return jax.tree_util.tree_map(lambda v: jax.device_put(v, sh), x)
-    dev = jax.devices()[0]
-    mem = dev.memory(HOST)
-    return jax.tree_util.tree_map(lambda v: jax.device_put(v, mem), x)
-
-
-def to_device(x: Any, mesh: Optional[jax.sharding.Mesh] = None,
-              spec: Optional[PartitionSpec] = None) -> Any:
-    if mesh is not None:
-        sh = device_sharding(mesh, spec if spec is not None else PartitionSpec())
-        return jax.tree_util.tree_map(lambda v: jax.device_put(v, sh), x)
-    dev = jax.devices()[0]
-    mem = dev.memory(DEVICE)
-    return jax.tree_util.tree_map(lambda v: jax.device_put(v, mem), x)
+    return "<host>" in jaxpr
 
 
 # ---------------------------------------------------------------------------

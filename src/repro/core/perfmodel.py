@@ -54,6 +54,24 @@ KNL = HardwareSpec(name="knl", peak_flops=3.0e12, hbm_bw=450e9,
 CPU_SSD = HardwareSpec(name="cpu-ssd", peak_flops=1.0e12, hbm_bw=100e9,
                        d2h_bw=2e9, hbm_bytes=64e9)       # DRAM -> SSD
 
+# TPU constants by ``device.device_kind`` (as JAX reports it).  A TPU whose
+# kind is missing here has no constants, and using v5e's would be wrong.
+TPU_BY_KIND = {"TPU v5 lite": TPU_V5E}
+
+
+def hardware_for(device) -> HardwareSpec:
+    """Roofline constants for a JAX device: the TPU table entry for its
+    ``device_kind`` (an unknown TPU kind raises), or the paper's KNL host
+    for a CPU."""
+    if device.platform != "tpu":
+        return KNL
+    try:
+        return TPU_BY_KIND[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware constants for TPU kind {device.device_kind!r}; "
+            f"known: {sorted(TPU_BY_KIND)}") from None
+
 
 # ---------------------------------------------------------------------------
 

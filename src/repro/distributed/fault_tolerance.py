@@ -96,6 +96,23 @@ def reshard_state(state: Any, new_mesh: Mesh, pspec_fn: Callable) -> Any:
     return jax.tree_util.tree_map_with_path(one, state)
 
 
+# Status prefixes of runtime errors that a retry repeats exactly: the
+# program does not fit in device memory, or the compiler cannot build it.
+_PERMANENT_STATUS = ("RESOURCE_EXHAUSTED", "UNIMPLEMENTED")
+
+
+def is_permanent(err: BaseException) -> bool:
+    """Whether re-running the step would fail the same way: out of device
+    memory, or a lowering/compile error (``NotImplementedError`` from a
+    lowering rule, or an XLA/Mosaic compile failure)."""
+    if isinstance(err, NotImplementedError):
+        return True
+    if not isinstance(err, jax.errors.JaxRuntimeError):
+        return False
+    msg = str(err)
+    return msg.startswith(_PERMANENT_STATUS) or "failed to compile" in msg
+
+
 def with_retries(fn: Callable, *, retries: int = 3,
                  on_retry: Optional[Callable[[int, Exception], None]] = None,
                  recover: Optional[Callable[[int, Exception], None]] = None):
@@ -113,6 +130,9 @@ def with_retries(fn: Callable, *, retries: int = 3,
     last durable boundary, so the retried step reproduces the gradient it
     would have produced, bit for bit.  An exception from ``recover``
     aborts the retry loop (a broken recovery path must not silently spin).
+    Failures a retry would only repeat (:func:`is_permanent`) raise at
+    once: re-running a step that donated its buffers would otherwise bury
+    the real error under "Array has been deleted".
     """
 
     def wrapped(*a, **kw):
@@ -120,7 +140,7 @@ def with_retries(fn: Callable, *, retries: int = 3,
             try:
                 return fn(*a, **kw)
             except (RuntimeError, jax.errors.JaxRuntimeError) as e:
-                if attempt == retries:
+                if attempt == retries or is_permanent(e):
                     raise
                 if on_retry is not None:
                     on_retry(attempt, e)

@@ -43,11 +43,12 @@ contribution once at the end — the exact association of the compiled
 runner's chunk-checkpointed transpose.  Uneven tails are a shorter static
 chunk, never a masked pad (``x + 0.0`` is not even bitwise-neutral).
 
-CPU has no Pallas lowering for the DMA path, so :func:`runner_supported`
-gates the runner: on non-TPU backends the front-end falls back to the
-compiled engine with a one-line warning, while tests/benchmarks opt into
-``interpret=True`` (Python-evaluated kernels, same numerics) via
-``REPRO_PALLAS_INTERPRET=1``.
+The kernels run in interpret mode only.  Mosaic refuses their per-chunk
+``lax.scan`` over chunk inputs, so on a TPU :func:`runner_supported` says
+no and the front-end raises.  CPU has no Pallas lowering for the DMA path:
+there the front-end falls back to the compiled engine with a one-line
+warning, while tests/benchmarks opt into ``interpret=True``
+(Python-evaluated kernels, same numerics) via ``REPRO_PALLAS_INTERPRET=1``.
 """
 from __future__ import annotations
 
@@ -82,12 +83,21 @@ def _force_interpret() -> bool:
 def runner_supported() -> Tuple[bool, str]:
     """Whether the fused pallas runner can execute on this jax backend.
 
-    Returns ``(ok, reason)``; ``reason`` is the one-line fallback message the
-    front-end warns with when ``ok`` is False.
+    Returns ``(ok, reason)``.  On a TPU the answer is no: Mosaic has no
+    lowering for a ``lax.scan`` over chunk inputs inside a kernel
+    (``_scan_lowering_rule`` raises ``NotImplementedError`` for extensive
+    inputs), which both kernels' chunk loops are — ``reason`` says so and
+    the front-end raises it.  Elsewhere the kernels run in interpret mode
+    when :data:`_FORCE_INTERPRET_ENV` is set, and ``reason`` is otherwise
+    the one-line fallback message the front-end warns with.
     """
     backend = jax.default_backend()
     if backend == "tpu":
-        return True, ""
+        return False, (
+            "runner='pallas' does not compile for a TPU: Mosaic cannot "
+            "lower the fused kernels' lax.scan over chunk inputs "
+            "(NotImplementedError in _scan_lowering_rule for extensive "
+            "inputs); use runner='compiled'")
     if _force_interpret():
         return True, ""
     return False, (

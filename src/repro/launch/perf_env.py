@@ -13,6 +13,9 @@ clobbering anything the user already set (user-set flags win), and
 warns when it can tell the jax backends are already initialised — at
 that point the flags are recorded but will not take effect until the
 next process.
+
+``configure_compile_cache`` is the one place an entry point turns on
+JAX's persistent compile cache.
 """
 from __future__ import annotations
 
@@ -101,3 +104,27 @@ def set_host_device_count(n: int, env: Optional[Mapping[str, str]] = None
                           ) -> List[str]:
     """Force ``n`` CPU devices (smoke-testing meshes without hardware)."""
     return configure_perf_env(host_device_count=n, env=env)
+
+
+# The persistent compile cache's default home.  The directory is part of
+# the cache key, so it is fixed: a path that moves between runs never hits.
+DEFAULT_COMPILE_CACHE = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
+    ".jax_cache"))
+
+
+def configure_compile_cache(env: Optional[Mapping[str, str]] = None
+                            ) -> Optional[str]:
+    """Turn on JAX's persistent compile cache for an entry point.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    this sets nothing (returns ``None``).  Otherwise the cache goes to
+    ``<checkout>/.jax_cache`` (git-ignored); returns that path."""
+    if env is None:
+        env = os.environ
+    if env.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE)
+    return DEFAULT_COMPILE_CACHE

@@ -27,6 +27,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.perf_env import configure_compile_cache
 from repro.models import get_model
 from repro.serve import DecodeSession
 
@@ -46,6 +47,7 @@ def main(argv=None):
                     help="disable cache donation so the session can be "
                     "parked/resumed (scheduler preemption)")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     api = get_model(cfg)

@@ -77,7 +77,8 @@ def main(argv=None):
                     help="offloaded-backprop strategy (None: plain autodiff)")
     ap.add_argument("--engine", default=None,
                     choices=("compiled", "interpreted", "scan"),
-                    help="execution engine behind --strategy")
+                    help="execution engine behind --strategy (default: scan on "
+                         "a TPU, compiled elsewhere)")
     ap.add_argument("--interval", type=int, default=None,
                     help="pin the Level-2 store interval I (None: autotune)")
     ap.add_argument("--slots", type=int, default=None,
@@ -144,9 +145,11 @@ def main(argv=None):
     # Overlap flags (latency-hiding scheduler, async collectives) and any
     # forced host device count must land in XLA_FLAGS before the first
     # backend init — do it before anything touches a jax device.
-    from repro.launch.perf_env import configure_perf_env
+    from repro.launch.perf_env import (configure_compile_cache,
+                                       configure_perf_env)
 
     configure_perf_env(host_device_count=args.host_devices)
+    configure_compile_cache()
 
     if args.strategy is not None and args.engine != "scan":
         # The executor engines escape the jitted step via io_callback and
@@ -197,6 +200,12 @@ def main(argv=None):
                  "--journal-dir/--step-memory-budget/--offload-params "
                  "configure an offloaded "
                  "strategy; pass --strategy as well")
+    if args.strategy is not None and args.engine is None:
+        # scan on a TPU (the executor engines cannot run there), else
+        # compiled — resolved here so the mesh choice below sees it
+        from repro.api import default_engine
+
+        args.engine = default_engine()
     if args.offload_params is not None:
         if args.engine in ("scan", "interpreted"):
             ap.error("--offload-params streams parameter blobs through the "
@@ -282,7 +291,7 @@ def main(argv=None):
         print(f"[mesh] data-parallel over {jax.device_count()} devices")
     elif mesh is None and jax.device_count() > 1:
         print(f"[mesh] {jax.device_count()} devices present but engine="
-              f"{args.engine or 'compiled'} escapes the trace; running "
+              f"{args.engine} escapes the trace; running "
               "single-device (use --engine scan to shard, or "
               "--sharded-offload for per-device Level-2 streams)")
     def _recover(attempt, err):

@@ -69,9 +69,10 @@ def make_train_step(api: ModelAPI, optimizer: Optimizer, *,
     O(interval + slots) regardless of depth/sequence length.
 
     ``engine`` picks the execution engine behind an offloaded strategy (it
-    is merged into ``offload_opts``): the segment-compiled executor
-    (``"compiled"``, default — one XLA call per interval, O(n/I) host
-    dispatches per train step), the step-granular interpreter
+    is merged into ``offload_opts``; unset, ``api.default_engine()``
+    decides): the segment-compiled executor (``"compiled"``, the default
+    off a TPU — one XLA call per interval, O(n/I) host dispatches per
+    train step), the step-granular interpreter
     (``"interpreted"``), or the trace-native plan-driven scan
     (``"scan"`` — the whole step stays one XLA computation, so it is the
     one to use when the step is jitted with sharded in/out specs on a
@@ -87,26 +88,26 @@ def make_train_step(api: ModelAPI, optimizer: Optimizer, *,
     def loss_fn(params, batch):
         return api.train_loss(params, batch)
 
-    if engine is not None:
-        offload_opts = dict(offload_opts or {}, engine=engine)
-
     value_and_grad = jax.value_and_grad(loss_fn)
     if strategy is not None:
         if api.train_chain is None:
             raise ValueError(
                 f"model family {api.cfg.family!r} has no chain decomposition;"
                 " cannot use an offloaded strategy")
-        if grad_accum != 1 and \
-                (offload_opts or {}).get("engine") != "scan":
+        from repro.api import default_engine, value_and_grad_offloaded
+
+        opts = dict(offload_opts or {})
+        if engine is not None:
+            opts["engine"] = engine
+        opts.setdefault("engine", default_engine())
+        if grad_accum != 1 and opts["engine"] != "scan":
             raise ValueError(
                 "grad_accum with an offloaded strategy needs the "
                 "trace-native engine='scan' (the executor engines escape "
                 "the trace via io_callback and cannot run under the "
                 "microbatch lax.scan)")
-        from repro.api import value_and_grad_offloaded
-
         value_and_grad = value_and_grad_offloaded(
-            api.train_chain, strategy=strategy, **(offload_opts or {}))
+            api.train_chain, strategy=strategy, **opts)
 
     def grads_of(params, batch):
         if grad_accum == 1:

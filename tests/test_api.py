@@ -301,3 +301,21 @@ def test_train_step_strategy_rejects_unchained_family():
 def test_unknown_strategy_rejected():
     with pytest.raises(ValueError, match="unknown strategy"):
         api.OffloadConfig(strategy="nope")
+
+
+@pytest.mark.parametrize("engine", ["compiled", "interpreted"])
+def test_executor_engines_refused_on_tpu(engine, monkeypatch):
+    """On a TPU io_callback would run the executor engines' segments on
+    the host CPU: they raise naming engine='scan', which is the default
+    there — no silent switch of an engine the caller asked for."""
+    spec = api.ChainSpec(lambda p, b: (b["c0"], b["xs"]),
+                         lambda p, c, x, b: c + p["w"] * jnp.tanh(x + c),
+                         lambda p, c, b: jnp.sum(c), name="tpu-refusal")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert api.default_engine() == "scan"
+    with pytest.raises(NotImplementedError, match="engine='scan'"):
+        api.value_and_grad_offloaded(spec, engine=engine)
+    vg = api.value_and_grad_offloaded(spec)
+    assert vg.offload_config.engine == "scan"
+    monkeypatch.undo()
+    assert api.default_engine() == "compiled"

@@ -167,3 +167,16 @@ def test_tier_plan_annotations():
     tp_t = plan.tier_plan(capacity_bytes=100, state_bytes=100,
                           t_t_slow=3e-3, t_seg_reverse=1.1e-3)
     assert tp_t.prefetch_distance == 3
+
+
+def test_hardware_for_keys_tpus_by_device_kind():
+    from types import SimpleNamespace
+
+    v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert pm.hardware_for(v5e) is pm.TPU_V5E
+    cpu = SimpleNamespace(platform="cpu", device_kind="cpu")
+    assert pm.hardware_for(cpu) is pm.KNL
+    other = SimpleNamespace(platform="tpu", device_kind="TPU v4")
+    with pytest.raises(ValueError, match="TPU v4"):
+        pm.hardware_for(other)
+

@@ -218,6 +218,26 @@ def test_with_retries_recover_hook_runs_before_each_attempt():
     assert seen == [(0, 1), (1, 2)]
 
 
+@pytest.mark.parametrize("err", [
+    NotImplementedError("Unimplemented primitive in Pallas TPU lowering"),
+    jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: Ran out of memory"),
+    jax.errors.JaxRuntimeError("INTERNAL: Mosaic failed to compile TPU "
+                               "kernel"),
+])
+def test_with_retries_raises_permanent_errors_at_once(err):
+    """Out-of-memory and compile failures repeat on retry, and a retry
+    of a donating step would only report 'Array has been deleted'."""
+    calls = {"n": 0}
+
+    def fails():
+        calls["n"] += 1
+        raise err
+
+    with pytest.raises(type(err)):
+        with_retries(fails, retries=3)()
+    assert calls["n"] == 1
+
+
 @pytest.mark.slow
 def test_launcher_retries_through_injected_storage_fault(tmp_path):
     """End-to-end launcher recovery: a step that dies to an injected
