@@ -1088,7 +1088,8 @@ def _scan_loss(spec: ChainSpec, cfg: OffloadConfig
     boundaries stay in HBM: plain plan-segmented remat, same schedule."""
 
     def loss(params, batch):
-        carry0, xs = spec.prelude(params, batch)
+        with jax.named_scope(ofl.SCOPE_PRELUDE):
+            carry0, xs = spec.prelude(params, batch)
         n = chain_length(xs)
         tune = _resolve_scan_schedule(spec, cfg, params, carry0, xs, batch, n)
         plan = ms.segment_plan(n, tune.interval, tune.slots)
@@ -1102,7 +1103,8 @@ def _scan_loss(spec: ChainSpec, cfg: OffloadConfig
         carry_n, _ = multistage_scan(
             step, carry0, xs, plan=plan,
             offload=ofl.host_offload_supported())
-        return spec.readout(params, carry_n, batch)
+        with jax.named_scope(ofl.SCOPE_READOUT):
+            return spec.readout(params, carry_n, batch)
 
     return loss
 

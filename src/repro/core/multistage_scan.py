@@ -157,8 +157,10 @@ def multistage_scan(
             if tail:
                 groups.append((1, tail, nested))
 
-    return _run_groups(body, carry, xs, groups, offload=offload,
-                       unroll=unroll, boundary_name=boundary_name)
+    # every op of the chain, the loops over segments included
+    with jax.named_scope(ofl.SCOPE_SEGMENT):
+        return _run_groups(body, carry, xs, groups, offload=offload,
+                           unroll=unroll, boundary_name=boundary_name)
 
 
 def _plan_groups(plan: SegmentPlan) -> List[Tuple[int, int, Tuple[int, ...]]]:

@@ -33,6 +33,29 @@ FFN_PRE = "ffn_pre"
 DEVICE = "device"
 HOST = "pinned_host"
 
+# ``jax.named_scope`` names of the offloaded step's parts.  They land in the
+# ``op_name`` metadata of every op the part lowers to, which is how a device
+# trace finds a part's time; within a segment, ``phase_of`` tells the sweeps
+# apart.
+SCOPE_SEGMENT = "chain.segment"     # every chain step of every segment
+SCOPE_PRELUDE = "chain.prelude"     # the loss before the chain (embedding)
+SCOPE_READOUT = "chain.readout"     # the loss after it (final norm, head)
+SCOPE_OPTIMIZER = "optimizer"       # the update, clipping, grad_norm
+SCOPE_SSD = "ssd"                   # the SSD scan of a Mamba-2 layer
+SCOPES = (SCOPE_SEGMENT, SCOPE_PRELUDE, SCOPE_READOUT, SCOPE_OPTIMIZER,
+          SCOPE_SSD)
+
+
+def phase_of(op_name: str) -> str:
+    """The sweep an op belongs to, from JAX's own markers in its ``op_name``
+    metadata: a recomputed op sits under ``rematted_computation``, a
+    backward op under ``transpose(``, a forward op under neither."""
+    if "rematted_computation" in op_name:
+        return "recompute"
+    if "transpose(" in op_name:
+        return "backward"
+    return "forward"
+
 
 def tag(x: Any, name: str) -> Any:
     """Tag every leaf of a pytree with a residual name (identity op)."""

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.core import offload as ofl
 from repro.models.layers import DTypes, DEFAULT_DTYPES, dense, init_dense
 
 Params = Any
@@ -198,7 +199,8 @@ def mamba2_block(p: Params, x: jnp.ndarray, *, d_state: int = 128,
     Bg = Bc.reshape(Bsz, T, ngroups, d_state)
     Cg = Cc.reshape(Bsz, T, ngroups, d_state)
     h0 = state[1].astype(jnp.float32) if state is not None else None
-    y, hf = ssd_chunked(xh, dts, A, Bg, Cg, chunk=chunk, h0=h0)
+    with jax.named_scope(ofl.SCOPE_SSD):
+        y, hf = ssd_chunked(xh, dts, A, Bg, Cg, chunk=chunk, h0=h0)
     y = y + p["D"].astype(y.dtype)[None, None, :, None] * xh
     y = y.reshape(Bsz, T, d_inner)
     y = _gated_norm(p, y, z).astype(dt.compute)

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core import offload as ofl
 from repro.distributed import compression as comp
 from repro.models.model_factory import ModelAPI
 from repro.optim.optimizers import Optimizer
@@ -129,15 +130,16 @@ def make_train_step(api: ModelAPI, optimizer: Optimizer, *,
             lambda g: g * scale, grads)
 
     def apply_update(state, loss, grads):
-        new_params, new_opt = optimizer.update(
-            grads, state["opt"], state["params"], state["step"])
-        out = dict(state, params=new_params, opt=new_opt,
-                   step=state["step"] + 1)
-        metrics = {"loss": loss.astype(jnp.float32),
-                   "grad_norm": jnp.sqrt(sum(
-                       jnp.sum(jnp.square(g.astype(jnp.float32)))
-                       for g in jax.tree_util.tree_leaves(grads)))}
-        return out, metrics
+        with jax.named_scope(ofl.SCOPE_OPTIMIZER):
+            new_params, new_opt = optimizer.update(
+                grads, state["opt"], state["params"], state["step"])
+            out = dict(state, params=new_params, opt=new_opt,
+                       step=state["step"] + 1)
+            metrics = {"loss": loss.astype(jnp.float32),
+                       "grad_norm": jnp.sqrt(sum(
+                           jnp.sum(jnp.square(g.astype(jnp.float32)))
+                           for g in jax.tree_util.tree_leaves(grads)))}
+            return out, metrics
 
     if cross_pod == "int8_ef":
         if mesh is None or "pod" not in mesh.axis_names:
