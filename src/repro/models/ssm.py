@@ -12,8 +12,10 @@ makes the ``long_500k`` shape feasible, and the chain whose per-chunk states
 are exactly the paper's uniform checkpoints: ``multistage_scan`` over the
 chunk axis offloads every I-th chunk state to host memory.
 
-``ssd_sequential`` is the O(T) oracle used by tests; the Pallas kernel in
-``repro.kernels.ssd_scan`` mirrors ``ssd_chunked`` on-chip.
+``ssd_sequential`` is the O(T) oracle used by tests.  The Pallas kernel in
+``repro.kernels.ssd_scan`` runs the same chunked algorithm one head at a
+time, on B and C repeated per head (``ssd_chunked`` keeps them per group);
+no model calls it.
 """
 from __future__ import annotations
 
@@ -63,43 +65,53 @@ def ssd_sequential(x, dt, A, B, C, h0=None):
 
 
 def ssd_chunked(x, dt, A, B, C, *, chunk: int = 64, h0=None):
-    """Chunked SSD (Mamba-2 alg.).  Same contract as ``ssd_sequential``."""
+    """Chunked SSD (Mamba-2 alg.).  Same contract as ``ssd_sequential``.
+
+    B and C stay at group granularity: heads are split as (group, head in
+    group), ``h = g * (H // G) + r``, and every contraction with B or C runs
+    per group.  The C.B scores are formed once per group and meet the
+    per-head decay only in the elementwise product that masks them.  The
+    per-head decays of the chunk states (``exp(ca_last - ca)``) and of the
+    inter-chunk output (``exp(ca)``) go on the (..., H, P) side: on x before
+    the state contraction, on the output after it.  So no (..., H, N) copy
+    of B or C exists, and autodiff saves and differentiates per-group B and
+    C only.
+    """
     b, T, H, P = x.shape
     G, N = B.shape[2], B.shape[3]
-    rep = H // G
+    R = H // G
     if T % chunk != 0:
         chunk = T
     nc = T // chunk
 
-    xf = x.astype(jnp.float32).reshape(b, nc, chunk, H, P)
-    dtf = dt.astype(jnp.float32).reshape(b, nc, chunk, H)
+    xf = x.astype(jnp.float32).reshape(b, nc, chunk, G, R, P)
+    dtf = dt.astype(jnp.float32).reshape(b, nc, chunk, G, R)
     Bf = B.astype(jnp.float32).reshape(b, nc, chunk, G, N)
     Cf = C.astype(jnp.float32).reshape(b, nc, chunk, G, N)
-    la = dtf * A[None, None, None, :]          # log a  (b,c,l,h)
+    la = dtf * A.reshape(G, R)                 # log a  (b,c,l,g,r)
     ca = jnp.cumsum(la, axis=2)                # cumulative within chunk
     xbar = xf * dtf[..., None]                 # dt-weighted input
 
     # ---- intra-chunk (dual / attention-like form) --------------------------
-    Bh = jnp.repeat(Bf, rep, axis=3)           # (b,c,l,H,n)
-    Ch = jnp.repeat(Cf, rep, axis=3)
-    cb = jnp.einsum("bclhn,bcshn->bchls", Ch, Bh)
-    seg = ca[..., :, None, :] - ca[..., None, :, :]        # (b,c,l,s,h)
+    cb = jnp.einsum("bclgn,bcsgn->bcgls", Cf, Bf)          # per group
+    ca_t = jnp.moveaxis(ca, 2, -1)                          # (b,c,g,r,l)
+    seg = ca_t[..., :, None] - ca_t[..., None, :]           # (b,c,g,r,l,s)
     li = jnp.arange(chunk)
     causal = li[:, None] >= li[None, :]
     # mask BEFORE exp: exp of masked (positive) entries overflows and the
     # where-VJP would produce 0 * inf = NaN gradients otherwise.
-    seg = jnp.where(causal[None, None, :, :, None], seg, -jnp.inf)
-    decay = jnp.exp(seg)
-    M = cb * decay.transpose(0, 1, 4, 2, 3)                # (b,c,h,l,s)
-    y_intra = jnp.einsum("bchls,bcshp->bclhp", M, xbar)
+    seg = jnp.where(causal, seg, -jnp.inf)
+    M = cb[:, :, :, None] * jnp.exp(seg)                    # (b,c,g,r,l,s)
+    y_intra = jnp.einsum("bcgrls,bcsgrp->bclgrp", M, xbar)
 
     # ---- chunk states -------------------------------------------------------
-    last = ca[:, :, -1:, :]                                 # (b,c,1,h)
-    dec_to_end = jnp.exp(last - ca)                         # (b,c,l,h)
-    S_c = jnp.einsum("bclhn,bclhp->bchpn", Bh * dec_to_end[..., None], xbar)
+    last = ca[:, :, -1:]                                    # (b,c,1,g,r)
+    dec_to_end = jnp.exp(last - ca)[..., None]              # (b,c,l,g,r,1)
+    S_c = jnp.einsum("bclgn,bclgrp->bcgrpn", Bf, xbar * dec_to_end)
+    S_c = S_c.reshape(b, nc, H, P, N)
 
     # ---- inter-chunk scan ----------------------------------------------------
-    chunk_decay = jnp.exp(last[:, :, 0, :])                 # (b,c,h)
+    chunk_decay = jnp.exp(last[:, :, 0]).reshape(b, nc, H)  # (b,c,h)
     if h0 is None:
         h0 = jnp.zeros((b, H, P, N), jnp.float32)
 
@@ -111,10 +123,10 @@ def ssd_chunked(x, dt, A, B, C, *, chunk: int = 64, h0=None):
     (hf, h_before) = lax.scan(
         pass_state, h0,
         (S_c.transpose(1, 0, 2, 3, 4), chunk_decay.transpose(1, 0, 2)))
-    h_before = h_before.transpose(1, 0, 2, 3, 4)            # (b,c,h,p,n)
+    h_before = h_before.transpose(1, 0, 2, 3, 4).reshape(b, nc, G, R, P, N)
 
-    y_inter = jnp.einsum("bclhn,bchpn->bclhp", Ch * jnp.exp(ca)[..., None],
-                         h_before)
+    dec_in = jnp.exp(ca)[..., None]                         # (b,c,l,g,r,1)
+    y_inter = jnp.einsum("bclgn,bcgrpn->bclgrp", Cf, h_before) * dec_in
     y = (y_intra + y_inter).reshape(b, T, H, P)
     return y.astype(x.dtype), hf
 

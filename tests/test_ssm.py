@@ -1,7 +1,10 @@
 """SSM: chunked SSD vs sequential oracle; block train path vs decode path."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _hypothesis_compat import given, settings, st  # optional dep, see shim
 
 from repro.models.layers import DTypes
@@ -99,3 +102,77 @@ def test_grads_finite():
 
     g = jax.grad(loss)(x)
     assert bool(jnp.all(jnp.isfinite(g)))
+
+
+@pytest.mark.parametrize("h,g,p,n,t,chunk", [
+    (4, 1, 8, 4, 64, 16),    # P > N
+    (8, 1, 4, 8, 64, 16),    # P < N
+    (8, 2, 8, 4, 64, 16),
+    (4, 4, 8, 8, 64, 16),    # G = H
+    (4, 2, 4, 8, 40, 16),    # T % chunk != 0: one chunk of all T
+])
+def test_chunked_grads_equal_sequential(h, g, p, n, t, chunk):
+    """Gradients of x, dt, A, B, C and h0 through ``ssd_chunked`` equal the
+    oracle's: B and C are contracted per group, so their gradients are the
+    sums over each group's heads."""
+    x, dt, A, B, C = _ssd_inputs(2, t, h, g, p, n, seed=h * 100 + g * 10 + p)
+    h0 = jax.random.normal(jax.random.fold_in(KEY, 77), (2, h, p, n)) * 0.3
+    wy = jax.random.normal(jax.random.fold_in(KEY, 78), (2, t, h, p))
+    wh = jax.random.normal(jax.random.fold_in(KEY, 79), (2, h, p, n))
+
+    def loss(fn):
+        def f(x, dt, A, B, C, h0):
+            y, hf = fn(x, dt, A, B, C, h0)
+            return jnp.sum(y * wy) + jnp.sum(hf * wh)
+        return f
+
+    args = (x, dt, A, B, C, h0)
+    ref = jax.jit(jax.grad(loss(lambda *a: ssd_sequential(*a[:5], h0=a[5])),
+                           argnums=range(6)))(*args)
+    got = jax.jit(jax.grad(loss(lambda *a: ssd_chunked(*a[:5], chunk=chunk,
+                                                       h0=a[5])),
+                           argnums=range(6)))(*args)
+    for name, r, c in zip(("x", "dt", "A", "B", "C", "h0"), ref, got):
+        assert c.shape == r.shape, name
+        np.testing.assert_allclose(np.array(c), np.array(r), rtol=1e-4,
+                                   atol=1e-4, err_msg=name)
+
+
+def _jaxpr_vars(jaxpr):
+    """Every variable bound in ``jaxpr`` and in its sub-jaxprs, with the
+    equation that binds it."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield eqn, v
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _jaxpr_vars(sub)
+
+
+def test_chunked_keeps_scores_and_bc_per_group():
+    """With one group, whether P < N or P > N, neither the per-head C.B
+    scores nor a head-broadcast copy of B or C is formed, forward or
+    backward."""
+    b, t, chunk, g, h = 2, 64, 16, 1, 8
+    nc = t // chunk
+    for p, n in ((4, 8), (6, 3)):
+        x, dt, A, B, C = _ssd_inputs(b, t, h, g, p, n)
+        f = functools.partial(ssd_chunked, chunk=chunk)
+
+        fwd = jax.make_jaxpr(f)(x, dt, A, B, C).jaxpr
+        scores = [v.aval.shape for e, v in _jaxpr_vars(fwd)
+                  if e.primitive.name == "dot_general"]
+        assert scores, "no contraction traced"
+        assert (b, nc, h, chunk, chunk) not in scores, (p, n)
+
+        def head_bc(shape):
+            # (b, nc, chunk, h, n) in any order, or split as (g, h // g)
+            return sorted(d for d in shape if d != 1) == sorted(
+                (b, nc, chunk, h, n))
+
+        def vjp(x, dt, A, B, C):
+            out, back = jax.vjp(f, x, dt, A, B, C)
+            return back(out)
+
+        for jaxpr in (fwd, jax.make_jaxpr(vjp)(x, dt, A, B, C).jaxpr):
+            shapes = [v.aval.shape for _, v in _jaxpr_vars(jaxpr)]
+            assert not [s for s in shapes if head_bc(s)], (p, n)
