@@ -7,11 +7,12 @@ For each of ``--seeds`` seeds it builds the cell's step as a run does,
 drives its first steps and compares them with the reference: the lower
 readings.  For the first ``--faulted-seeds`` of them it also reads the
 control (the reference in the precision below the configuration's, in the
-program's place) and each planted fault of the timed path: the upper
-readings.  ``--witness N`` reads, on the first N seeds, the reference that
-rounds what the system stores in bfloat16 (where the configuration's
-reference has that mode) against the fp32 reference, and prints each
-leaf's ``grad_err`` beside the program's on the same seed.  A state left unchanged reads 1 on ``grad_gap`` and
+program's place) and each planted fault that the cell's timed path can
+have (``harness.train.faults_of``): the upper readings.  ``--witness N``
+reads, on the first N seeds, the reference that rounds what the system
+stores in bfloat16 (where the configuration's reference has that mode)
+against the fp32 reference, and prints each leaf's ``grad_err`` beside the
+program's on the same seed.  A state left unchanged reads 1 on ``grad_gap`` and
 ``update_gap`` by construction and is printed without a run.  Each reading
 is a JSON line on standard output; the last line sums them up per number:
 the largest sound reading, and the smallest reading of the control and of
@@ -65,7 +66,7 @@ def main(argv=None) -> int:
 
     from harness import check
     from harness.spec import load_cell
-    from harness.train import Trainer, reference
+    from harness.train import Trainer, faults_of, reference
 
     cell = load_cell(ROOT, args.workload)
     if not args.smoke and (jax.devices()[0].platform != "tpu"
@@ -106,7 +107,9 @@ def main(argv=None) -> int:
         note("control", seed, check.compare(ctl, ref), time.time() - t0)
         note("state_unchanged", seed,
              check.compare(check.unchanged_state_readings(ref), ref), 0.0)
-        for fault in ("half_batch", "grad_doubled"):
+        for fault in faults_of(cell):
+            if fault == "state_unchanged":
+                continue
             t0 = time.time()
             s = Trainer(cell, seed, smoke=args.smoke, fault=fault)
             bad = s.prog

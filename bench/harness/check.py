@@ -27,7 +27,8 @@ The numbers, each compared against its limit from
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, List, Optional
+from concurrent.futures import ThreadPoolExecutor
+from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -109,18 +110,55 @@ def adamw_reference(opt: Dict[str, Any]):
     return clip_grads, update
 
 
+def split_rows(loss_and_grad, devices: Sequence[Any]):
+    """``loss_and_grad`` over a batch's rows split evenly across
+    ``devices``, each part on its own device from a thread of its own; the
+    loss and the gradient are the mean over all rows, on the first
+    device."""
+    import jax
+    import jax.numpy as jnp
+
+    if len(devices) == 1:
+        return loss_and_grad
+
+    def split(params, tokens, sizes, *, control=False):
+        parts = np.array_split(np.asarray(tokens), len(devices))
+
+        def part(dev, rows):
+            with jax.default_device(dev):
+                loss, g = loss_and_grad(jax.device_put(params, dev), rows,
+                                        sizes, control=control)
+                return float(loss), g
+
+        with ThreadPoolExecutor(len(devices)) as pool:
+            outs = list(pool.map(part, devices, parts))
+        w = [len(p) / len(tokens) for p in parts]
+        loss = sum(wi * l for wi, (l, _) in zip(w, outs))
+        grads = jax.tree_util.tree_map(
+            lambda *gs: sum(wi * jax.device_put(g, devices[0])
+                            for wi, g in zip(w, gs)),
+            *[g for _, g in outs])
+        return jnp.float32(loss), grads
+
+    return split
+
+
 def reference_readings(ref, sizes: Dict[str, Any], key, batches: List[Any],
                        opt: Dict[str, Any], *, control: bool = False,
+                       devices: Sequence[Any],
                        rows: Optional[int] = None) -> Readings:
     """Three reference steps from ``ref.init_params(key, sizes)``.
 
     ``control=True`` computes in the precision below the configuration's
     (``ref.loss_and_grad(..., control=True)``).  ``rows`` keeps only the
-    first rows of each batch (a planted fault: half the batch left out)."""
+    first rows of each batch (a planted fault: half the batch left out).
+    With more than one of ``devices`` each step's rows are split across
+    them (``split_rows``)."""
     import jax
     import jax.numpy as jnp
 
     clip_grads, update = adamw_reference(opt)
+    loss_and_grad = split_rows(ref.loss_and_grad, devices)
     norms = leaf_norm_fn()
     params = ref.init_params(key, sizes)
     p0 = jax.tree_util.tree_map(jnp.copy, params)
@@ -129,7 +167,7 @@ def reference_readings(ref, sizes: Dict[str, Any], key, batches: List[Any],
     losses, first = [], None
     for k, b in enumerate(batches[:CHECK_STEPS]):
         tokens = b["tokens"] if rows is None else b["tokens"][:rows]
-        loss, g = ref.loss_and_grad(params, tokens, sizes, control=control)
+        loss, g = loss_and_grad(params, tokens, sizes, control=control)
         losses.append(float(loss))
         g = clip_grads(g)
         if first is None:

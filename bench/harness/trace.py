@@ -212,6 +212,34 @@ def host_copy_names(hlo_text: str) -> set:
     return starts | {n for n, src, host in dones if host or src in starts}
 
 
+_DEF = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*")
+_OPCODE = re.compile(r"\S*\s+([a-z][\w\-]*)\(")
+
+
+def opcodes_from_hlo(hlo_text: str) -> Dict[str, str]:
+    """HLO instruction name -> its opcode (``fusion``, ``all-reduce-start``,
+    ...), for every instruction of the module's text.  An instruction reads
+    ``%name = <shape> <opcode>(<operands>), ...``, where a tuple's shape is
+    itself in parentheses."""
+    out = {}
+    for line in hlo_text.splitlines():
+        m = _DEF.match(line)
+        if not m:
+            continue
+        i = m.end()
+        if line.startswith("(", i):           # a tuple shape
+            depth = 0
+            for i in range(i, len(line)):
+                depth += {"(": 1, ")": -1}.get(line[i], 0)
+                if depth == 0:
+                    break
+            i += 1
+        op = _OPCODE.match(line, i)
+        if op:
+            out[m.group(1)] = op.group(1)
+    return out
+
+
 def dump(trace: Trace, path: str) -> None:
     with open(path, "w") as f:
         json.dump(trace.to_json(), f)

@@ -10,6 +10,10 @@ thread, calls the step and reads the loss back, as ``repro.launch.train``
 does.  With ``--trace 1`` a few more steps run under the profiler.  Once
 the window has closed, the device memory has been read and the program's
 state is freed, the plain reference follows the first three steps.
+
+A cell of more than one chip trains data-parallel on a mesh of its chips:
+the state replicated, each batch split by rows, and the same step jitted,
+so that the compiler puts in the gradient's all-reduce.
 """
 from __future__ import annotations
 
@@ -24,9 +28,9 @@ import sys
 import tempfile
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from harness import check, tokens
+from harness import check, scopes, tokens
 from harness import trace as tr
 from harness.peaks import peaks_for
 from harness.spec import Cell
@@ -35,7 +39,8 @@ from harness.spec import Cell
 # profiler keeps some 6.3 million device events (a TPU v5e), and one step of
 # lstm-paper.bptt-64k makes about 4 million.
 TRACE_TARGET_S = 2.0
-FAULTS = ("state_unchanged", "half_batch", "grad_doubled")
+FAULTS = ("state_unchanged", "half_batch", "grad_doubled",
+          "exchange_left_out")
 
 
 def log(**fields) -> None:
@@ -146,10 +151,18 @@ class CompileCounter:
         return dict(self.counts)
 
 
-def _planted(fault: Optional[str], raw_step):
+def faults_of(cell: Cell) -> Tuple[str, ...]:
+    """The faults that ``cell``'s timed path can have: the exchange between
+    chips only where there are chips to exchange between."""
+    return tuple(f for f in FAULTS
+                 if f != "exchange_left_out" or cell.chips > 1)
+
+
+def _planted(fault: Optional[str], raw_step, mesh):
     """The timed step broken underneath, for the fault tests
     (``grad_doubled`` is planted in the optimizer instead)."""
     import jax
+    from jax.sharding import PartitionSpec as P
 
     if fault == "state_unchanged":
         def step(state, batch):
@@ -161,6 +174,17 @@ def _planted(fault: Optional[str], raw_step):
             half = jax.tree_util.tree_map(lambda x: x[:x.shape[0] // 2],
                                           batch)
             return raw_step(state, half)
+        return step
+    if fault == "exchange_left_out":
+        if mesh is None:
+            raise ValueError("exchange_left_out needs a mesh of chips")
+
+        # each chip steps on its own rows' gradient, with no mean across
+        # chips; the state claims to be replicated and is not
+        def step(state, batch):
+            return jax.shard_map(raw_step, mesh=mesh,
+                                 in_specs=(P(), P("data")), out_specs=P(),
+                                 check_vma=False)(state, batch)
         return step
     if fault not in (None, "grad_doubled"):
         raise ValueError(f"unknown fault {fault!r} (have {FAULTS})")
@@ -203,6 +227,9 @@ class Trainer:
                  fault: Optional[str] = None):
         import jax
         import jax.numpy as jnp
+        import numpy as np
+        from jax.sharding import Mesh, NamedSharding
+        from jax.sharding import PartitionSpec as P
 
         from repro import api
         from repro.configs import get_config
@@ -220,21 +247,27 @@ class Trainer:
         model = get_model(get_config(cell.config["program"]["arch"],
                                      smoke=smoke))
         opt = _optimizer(cfg_opt, fault)
+        self.devices = jax.devices()
+        self.used = self.devices[:cell.chips]
+        if cell.chips > 1:
+            mesh = Mesh(np.array(self.used), ("data",))
+            state_sh = NamedSharding(mesh, P())
+            rows_sh = NamedSharding(mesh, P("data"))
+        else:
+            mesh = None
+            state_sh = rows_sh = jax.sharding.SingleDeviceSharding(
+                self.devices[0])
         raw = make_train_step(
             model, opt, strategy=shape.strategy, engine=shape.engine,
             offload_opts=({"interval": shape.interval}
                           if shape.interval is not None else None))
-        raw = _planted(fault, raw)
-
-        self.devices = jax.devices()
+        raw = _planted(fault, raw, mesh)
         marks.append(time.time())
-        self.used = self.devices[:cell.chips]
-        state_sh = jax.sharding.SingleDeviceSharding(self.devices[0])
         vocab = self.sizes["vocab_size"]
         self.host_batch = lambda step: tokens.batch(
             seed, step, shape.rows, shape.seq_len, vocab)
         proto = self.host_batch(0)
-        batch_sh = jax.tree_util.tree_map(lambda _: state_sh, proto)
+        batch_sh = jax.tree_util.tree_map(lambda _: rows_sh, proto)
 
         self.key = key_of(seed)
         state = jax.jit(lambda k: init_train_state(model, opt, k),
@@ -244,11 +277,14 @@ class Trainer:
         spec = jax.tree_util.tree_map(
             lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
             proto, batch_sh)
-        self.compiled = jax.jit(raw, donate_argnums=(0,)).lower(
-            state, spec).compile()
+        lowered = jax.jit(raw, donate_argnums=(0,)).lower(state, spec)
+        hits = self.counter.counts["cache_hits"]
+        self.compiled = lowered.compile()
+        self.step_from_cache = self.counter.counts["cache_hits"] > hits
         marks.append(time.time())
         self.mem = self.compiled.memory_analysis()
-        self.copy_names = tr.host_copy_names(self.compiled.as_text())
+        self.hlo_text = self.compiled.as_text()
+        self.copy_names = tr.host_copy_names(self.hlo_text)
         tune = api.last_tune() if shape.strategy else None
         self.interval = None if tune is None else tune.interval
         norms = check.leaf_norm_fn()
@@ -341,7 +377,8 @@ class Trainer:
         out = {"trace": t, "steps": tr.steps_in(t),
                "window_s": (hi - lo) / 1e9,
                "busy_s": tr.mean_busy_ns(t) / 1e9,
-               "top_ops": tr.top_ops(t), "idle_gaps": tr.idle_gaps(t)}
+               "top_ops": tr.top_ops(t), "idle_gaps": tr.idle_gaps(t),
+               "name_ns": scopes.name_ns(t)}
         log(traced_steps=n, trace_s=t1 - t0, parse_s=t2 - t1,
             reduce_s=time.time() - t2,
             device_events={k: len(v) for k, v in t.device_ops.items()},
@@ -363,14 +400,18 @@ class Trainer:
 def reference(cell: Cell, seed: int, *, smoke: bool = False,
               control: bool = False, rows: Optional[int] = None
               ) -> check.Readings:
-    """The plain reference over the first steps of ``seed``'s batches."""
+    """The plain reference over the first steps of ``seed``'s batches,
+    each step's rows split across the cell's chips."""
+    import jax
+
     shape, sizes = shape_of(cell, smoke), sizes_of(cell, smoke)
     batches = [tokens.batch(seed, k, shape.rows, shape.seq_len,
                             sizes["vocab_size"])
                for k in range(check.CHECK_STEPS)]
     return check.reference_readings(cell.reference(), sizes, key_of(seed),
                                     batches, cell.config["optimizer"],
-                                    control=control, rows=rows)
+                                    control=control, rows=rows,
+                                    devices=jax.devices()[:cell.chips])
 
 
 def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
@@ -416,6 +457,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
                               "count": len(s.devices),
                               "memory_peak_bytes": peak}
     if trace:
+        op_names = scopes.op_names_from_hlo(s.hlo_text)
         device["busy_s"] = summary["busy_s"]
         device["window_s"] = summary["window_s"]
         metrics = _per_layer(cell, {
@@ -427,6 +469,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
             "memory": mem,
             "trace": summary,
             "host_copy_names": s.copy_names,
+            "op_names": op_names,
+            "opcodes": tr.opcodes_from_hlo(s.hlo_text),
+            "sizes": s.sizes,
+            "shape": dataclasses.asdict(shape),
         })
     else:
         metrics = {
@@ -458,6 +504,15 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         host_bytes=_host_bytes(mem),
         allocator_peak_bytes_in_use=peak)
     log(program_losses=prog.losses, reference_losses=ref.losses)
+    if trace:
+        parts = scopes.part_ms(summary["trace"], op_names,
+                               summary["name_ns"])
+        log(step_from_cache=s.step_from_cache,
+            hlo_ops_in_scope={sc: sum(1 for n in op_names.values()
+                                      if scopes.in_scope(n, sc))
+                              for sc in scopes.SCOPES},
+            parts_busy_ms=parts["busy_ms"],
+            parts_unscoped_ms=parts["unscoped_ms"])
     out: Dict[str, Any] = {"correct": bool(correct),
                            "attempted": len(in_window), "failed": failed,
                            "metrics": metrics, "device": device}

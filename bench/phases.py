@@ -15,11 +15,11 @@ inside loops with the part of their loop, the plan's counters
 untraced and under the profiler.  The last line of standard output holds
 the same numbers as JSON.
 
-The persistent compile cache is off here: its key leaves out op metadata,
-so a program compiled before the scopes existed could be loaded in their
-place.  ``--record PATH`` writes the reduced trace with the ``op_name`` of
-each op in it (for the tests; use ``--smoke``).
-The benchmark's own runs never run this.
+The persistent compile cache is the one runs use
+(``run_cell.configure_cache``), whose key holds the op metadata.
+``--record PATH`` writes the reduced trace with the ``op_name`` of each op
+in it (for the tests; use ``--smoke``).  The benchmark's own runs never
+run this.
 """
 import argparse
 import json
@@ -79,9 +79,11 @@ def main(argv=None) -> int:
 
     sys.path.insert(0, BENCH)
     sys.path.insert(0, os.path.join(ROOT, "src"))
+    from run_cell import configure_cache
+
+    configure_cache()
     import jax
 
-    jax.config.update("jax_enable_compilation_cache", False)
     from harness import scopes as sc
     from harness.spec import load_cell
     from harness.train import Trainer
@@ -108,7 +110,7 @@ def main(argv=None) -> int:
     t = summary["trace"]
     t0 = time.time()
     in_trace = {name for ops in t.device_ops.values() for name, _, _ in ops}
-    ns = sc.name_ns(t)
+    ns = summary["name_ns"]
     parts = sc.part_ms(t, op_names, ns)
     busy = parts.pop("busy_ms")
     unscoped = parts.pop("unscoped_ms")

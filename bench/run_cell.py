@@ -26,12 +26,16 @@ ROOT = os.path.dirname(BENCH)
 
 def configure_cache() -> str:
     """JAX's persistent compile cache at a fixed path: the one
-    ``JAX_COMPILATION_CACHE_DIR`` names, else ``<checkout>/.jax_cache``."""
+    ``JAX_COMPILATION_CACHE_DIR`` names, else ``<checkout>/.jax_cache``.
+    Its key holds the op metadata, so that two checkouts whose steps
+    differ only in their named scopes, sharing one such directory, never
+    load each other's step: the part metrics read that metadata."""
     import jax
 
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
         ROOT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return path

@@ -6,8 +6,9 @@ import json
 import os
 import shutil
 import time
+import types
 
-from harness import spec
+from harness import scopes, spec
 from harness.train import _per_layer, run
 
 from conftest import BENCH, ROOT
@@ -24,12 +25,23 @@ def _digests(root):
     return out
 
 
-def test_new_cell_from_new_files(tmp_path):
+# every cell reports these, those that later PRs add too: no ``workloads``
+# list names the cells
+ALL_CELLS = ["mfu", "device_idle_share", "l2_copy_ms", "l2_host_gb",
+             *scopes.PARTS]
+
+
+def _copy(tmp_path):
     root = tmp_path / "checkout"
     bench = root / "bench"
     shutil.copytree(BENCH, bench,
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
+    return root, bench
+
+
+def test_new_cell_from_new_files(tmp_path):
+    root, bench = _copy(tmp_path)
     before = _digests(bench)
 
     # a configuration: the paper's LSTM at half its hidden size
@@ -67,14 +79,36 @@ def test_new_cell_from_new_files(tmp_path):
     cell = spec.load_cell(str(root), "lstm-half.bptt-1k", str(bench))
     assert cell.config["sizes"]["hidden_size"] == 128
     assert cell.traffic["seq_len"] == 1024
-    assert [m.name for m in cell.per_layer] == ["mfu", "device_idle_share",
-                                                "steps_per_s"]
+    assert [m.name for m in cell.per_layer] == ALL_CELLS + ["steps_per_s"]
     out = run(cell, 2 ** 31 + 3, 0.2, False, t_start=time.time(),
               smoke=True)
     assert out["correct"] is True, out["check"]
+    no_host = types.SimpleNamespace(
+        host_argument_size_in_bytes=0, host_output_size_in_bytes=0,
+        host_temp_size_in_bytes=0, host_alias_size_in_bytes=0)
     got = _per_layer(cell, {"tokens_per_s": 6400.0, "peaks": None,
-                            "trace": None})
+                            "trace": None, "memory": no_host,
+                            "host_copy_names": set()})
     assert got == {"steps_per_s": {"value": 100.0, "unit": "1/s"}}
 
     after = _digests(bench)
     assert {k: v for k, v in after.items() if k in before} == before
+
+
+def test_a_cell_added_by_entry_alone_reports_parts_and_level2(tmp_path):
+    """A third cell written into ``BENCHMARK.json``, on files that are
+    there, gets the six part metrics and both Level-2 metrics, and no
+    metric that a ``workloads`` list keeps to other cells."""
+    root, bench = _copy(tmp_path)
+    b = json.loads((root / "BENCHMARK.json").read_text())
+    b["workloads"].append({"name": "mamba2-370m.offload-2k-again",
+                           "config": "mamba2-370m", "traffic": "offload-2k",
+                           "chips": 1, "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(b))
+    shutil.copy(bench / "cells" / "mamba2-370m.offload-2k.json",
+                bench / "cells" / "mamba2-370m.offload-2k-again.json")
+    cell = spec.load_cell(str(root), "mamba2-370m.offload-2k-again",
+                          str(bench))
+    assert [m.name for m in cell.per_layer] == ALL_CELLS
+    assert [m.name for m in cell.end_to_end] == ["tokens_per_s",
+                                                 "device_mem_gb", "setup_s"]
